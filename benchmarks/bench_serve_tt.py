@@ -37,6 +37,9 @@ measurement, asserting zero TT plan re-resolutions and paged≡dense token
 identity on every mesh — see ``_mesh_scaling`` for how the single-core
 container's forced serialization is reported vs corrected.  Results land
 in ``results/BENCH_serve.json``.
+
+Everything here runs on the CPU, parent and children alike: its timings
+are CPU timings, never chip results.
 """
 from __future__ import annotations
 
@@ -148,6 +151,14 @@ def _prefix_workload(model, params, n_req, prefix_len, tail, steps):
     return out
 
 
+def _cpu_child_env(repo: pathlib.Path) -> dict:
+    """Environment of a child process: the repo on ``PYTHONPATH`` and JAX
+    pinned to the CPU, like the parent (see :func:`run`)."""
+    return dict(os.environ, JAX_PLATFORMS="cpu",
+                PYTHONPATH=str(repo / "src") + os.pathsep
+                + os.environ.get("PYTHONPATH", ""))
+
+
 def _cold_start(arch: str = "deepseek-7b", prompt_len: int = 8,
                 steps: int = 4) -> dict:
     """Process start → first token, cold vs warm, via two real serve.py
@@ -156,9 +167,7 @@ def _cold_start(arch: str = "deepseek-7b", prompt_len: int = 8,
     carries --assert-cache-hits so zero-recompile is enforced inside the
     measured process itself."""
     repo = pathlib.Path(__file__).resolve().parent.parent
-    env = dict(os.environ,
-               PYTHONPATH=str(repo / "src") + os.pathsep
-               + os.environ.get("PYTHONPATH", ""))
+    env = _cpu_child_env(repo)
 
     def launch(cache_dir: str, warm: bool) -> dict:
         cmd = [sys.executable, "-m", "repro.launch.serve", "--arch", arch,
@@ -327,9 +336,7 @@ def _mesh_scaling(quick: bool) -> dict:
     rounds = 1 if quick else 3
     counts = (1, 2, 4)
     repo = pathlib.Path(__file__).resolve().parent.parent
-    env = dict(os.environ,
-               PYTHONPATH=str(repo / "src") + os.pathsep
-               + os.environ.get("PYTHONPATH", ""))
+    env = _cpu_child_env(repo)
 
     meas: dict[int, list[dict]] = {n: [] for n in counts}
     for r in range(rounds):
@@ -495,6 +502,15 @@ def _ttft_adversary(quick: bool) -> dict:
 
 
 def run(quick: bool = False) -> None:
+    # This bench is the CPU rehearsal of the serving path.  It pins itself
+    # and every child process to the CPU: on a TPU host a parent that has
+    # touched the chip would hold it while its children start.
+    jax.config.update("jax_platforms", "cpu")
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "bench_serve_tt is the CPU rehearsal, but JAX is already "
+            "running on the TPU in this process — run it with "
+            "JAX_PLATFORMS=cpu")
     S, steps = 16, (8 if quick else 16)
     slot_counts = [2] if quick else [1, 2, 4, 8]
     archs = ["deepseek_7b"] if quick else ["deepseek_7b", "qwen3_32b",

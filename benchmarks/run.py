@@ -38,12 +38,10 @@ def main() -> None:
     ap.add_argument("--only", default=None, choices=BENCHES)
     args = ap.parse_args()
 
-    # $REPRO_COMPILE_CACHE (launch.cache): benchmark reruns skip every
-    # compile a previous invocation already paid for
+    # persistent compile cache (launch.cache): benchmark reruns skip
+    # every compile a previous invocation already paid for
     from repro.launch.cache import enable_compile_cache
-    cache_dir = enable_compile_cache()
-    if cache_dir:
-        print(f"# persistent compile cache: {cache_dir}")
+    print(f"# persistent compile cache: {enable_compile_cache()}")
 
     names = [args.only] if args.only else BENCHES
     t_all = time.time()

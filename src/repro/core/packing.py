@@ -53,6 +53,20 @@ def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
 
+# Row multiple of every step-kernel tile: the sublane tile of int8
+# operands (bf16 needs 16, fp32 8), so one rule suits every dtype.
+ROW_TILE = 32
+
+
+def step_tiles(bm: int, bb: int, bn: int, rt: int, rt_1: int
+               ) -> tuple[int, int, int]:
+    """The 2-D tiles the step kernel runs a (bm, bb, bn) block as: rows of
+    X, the contraction ``n·r_t`` and the output columns ``m·r_{t-1}``,
+    each rounded up to the TPU (row, lane) tile that Mosaic requires."""
+    return (_round_up(bb, ROW_TILE), _round_up(bn * rt, hw.LANES),
+            _round_up(bm * rt_1, hw.LANES))
+
+
 def _divisors_pow2(n: int, lo: int, hi: int):
     v = lo
     while v <= min(n, hi):
@@ -75,10 +89,12 @@ def select_blocks(mt: int, bt: int, nt: int, rt: int, rt_1: int,
       bytes(X)   = ceil(b/bb) … X re-read once per *m*-tile
       bytes(out) = written once
 
-    Minimize total subject to double-buffered VMEM residency:
-      2·(bm·bn·rt·rt_1 + bb·bn·rt + bm·bb·rt_1)·itemsize ≤ budget.
-    Alignment: last dim padded to the 128-lane register shape, second-minor
-    to 8 sublanes (the TPU analogue of the paper's vl-multiple rule).
+    Minimize total subject to double-buffered VMEM residency of the tiles
+    the kernel really holds (:func:`step_tiles`: the packed-core tile
+    ``[bn·rt, bm·rt_1]``, the X tile ``[bb, bn·rt]`` and the fp32 output
+    tile ``[bb, bm·rt_1]``, lane dims padded to 128 and rows to
+    ``ROW_TILE`` — the TPU analogue of the paper's vl-multiple rule):
+      2·(w·tk·tn + itemsize·(tb·tk + tb·tn)) ≤ budget.
 
     ``weight_itemsize`` prices the resident G tile separately from the
     activation tiles (int8-resident cores: 1 byte/elem, DESIGN.md §8).
@@ -108,8 +124,8 @@ def select_blocks_candidates(mt: int, bt: int, nt: int, rt: int, rt_1: int,
     for bm in _divisors_pow2(mt, 8, 512):
         for bb in _divisors_pow2(bt, 8, 1024):
             for bn in _divisors_pow2(nt, 8, 2048):
-                vmem = 2 * (w_item * bm * bn * rt * rt_1
-                            + itemsize * (bb * bn * rt + bm * bb * rt_1))
+                tb, tk, tn = step_tiles(bm, bb, bn, rt, rt_1)
+                vmem = 2 * (w_item * tk * tn + itemsize * (tb * tk + tb * tn))
                 if vmem > vmem_budget:
                     continue
                 n_mtiles = -(-mt // bm)
@@ -121,25 +137,6 @@ def select_blocks_candidates(mt: int, bt: int, nt: int, rt: int, rt_1: int,
                           g_total + x_total + o_total, 0)]
     cands.sort(key=lambda c: (c.traffic_bytes, -c.vmem_bytes))
     return cands[:k]
-
-
-def chain_fits_vmem(plan_sizes: list[int], itemsize: int = 4,
-                    vmem_budget: int = hw.VMEM_BUDGET_BYTES,
-                    weight_elems: int = 0,
-                    weight_itemsize: int | None = None) -> bool:
-    """Paper Eq. (26) analogue: can the whole einsum chain for one batch
-    tile stay resident in VMEM (weights + largest two consecutive states)?
-
-    ``plan_sizes`` are the element counts of the chain states s_0 … s_d for
-    one batch tile; ``weight_elems`` is the total element count of the
-    packed cores (held once, not double-buffered) priced at
-    ``weight_itemsize`` bytes/elem (defaults to ``itemsize``; int8-resident
-    cores pass 1, which is what buys the enlarged eligibility set)."""
-    w_item = itemsize if weight_itemsize is None else weight_itemsize
-    peak = 0
-    for a, b in zip(plan_sizes, plan_sizes[1:]):
-        peak = max(peak, a + b)
-    return peak * itemsize * 2 + weight_elems * w_item <= vmem_budget
 
 
 @dataclasses.dataclass(frozen=True)
@@ -194,40 +191,41 @@ def chain_weight_elems(ns, ms, ranks) -> int:
                for t in range(len(ns)))
 
 
+def fused_chain_vmem_bytes(bb: int, ns, ms, ranks, itemsize: int = 4,
+                           weight_itemsize: int | None = None) -> int:
+    """VMEM the fused-chain kernel holds for one batch tile of ``bb`` rows
+    (``kernels.tt_contract``): the double-buffered ``x``/``y`` tiles, the
+    fp32 state pair with the relayout copy of each (tokens on lanes), the
+    ``[M, 128]`` scratch that interleaves the output, the double-buffered
+    packed cores and one core widened to fp32 for the MXU.  ``itemsize``
+    prices activations and states, ``weight_itemsize`` the cores."""
+    w_item = itemsize if weight_itemsize is None else weight_itemsize
+    sizes = chain_state_sizes(ns, ms, ranks)
+    peak = max(a + b for a, b in zip(sizes, sizes[1:]))
+    widest = max(ns[t] * ranks[t + 1] * ms[t] * ranks[t]
+                 for t in range(len(ns)))
+    return (2 * itemsize * bb * (sizes[0] + sizes[-1])
+            + 2 * itemsize * bb * peak
+            + itemsize * sizes[-1] * hw.LANES
+            + 2 * w_item * chain_weight_elems(ns, ms, ranks)
+            + 4 * widest)
+
+
 def fused_chain_batch_tile(ns, ms, ranks, itemsize: int = 4,
                            vmem_budget: int = hw.VMEM_BUDGET_BYTES,
                            weight_itemsize: int | None = None
                            ) -> int | None:
-    """Largest power-of-two batch tile for which the *whole* chain is
-    VMEM-resident (packed weights + double-buffered peak state pair), or
-    ``None`` when even the minimum 8-row tile does not fit — the caller
-    must then fall back to the per-step kernel.  This is the fused-chain
-    analogue of the paper's L2-fit test (Eq. 26–28), routed through
-    ``chain_fits_vmem``.  ``weight_itemsize=1`` (int8-resident cores)
-    admits chains whose fp32 weights alone bust the budget."""
-    sizes = chain_state_sizes(ns, ms, ranks)
-    weights = chain_weight_elems(ns, ms, ranks)
+    """Largest power-of-two batch tile, from 1024 down to one lane width
+    (128 rows — the kernel puts tokens on lanes), whose
+    :func:`fused_chain_vmem_bytes` fits the budget, or ``None`` when even
+    128 rows do not — the caller must then fall back to the per-step
+    kernel.  This is the fused-chain analogue of the paper's L2-fit test
+    (Eq. 26–28).  ``weight_itemsize=1`` (int8-resident cores) admits
+    chains whose fp32 weights alone bust the budget."""
     bb = 1024
-    while bb >= 8:
-        if chain_fits_vmem([bb * s for s in sizes], itemsize, vmem_budget,
-                           weight_elems=weights,
-                           weight_itemsize=weight_itemsize):
+    while bb >= hw.LANES:
+        if fused_chain_vmem_bytes(bb, ns, ms, ranks, itemsize,
+                                  weight_itemsize) <= vmem_budget:
             return bb
         bb //= 2
     return None
-
-
-def fused2_batch_tile(N: int, M: int, mid: int, weights: int,
-                      itemsize: int = 4,
-                      vmem_budget: int = hw.VMEM_BUDGET_BYTES,
-                      weight_itemsize: int | None = None) -> int:
-    """Largest power-of-two batch tile such that X-tile + intermediate +
-    Y-tile + packed weights double-buffer in VMEM (fused d=2 kernel)."""
-    w_item = itemsize if weight_itemsize is None else weight_itemsize
-    bb = 1024
-    while bb > 8:
-        need = 2 * itemsize * (bb * (N + mid + M)) + w_item * weights
-        if need <= vmem_budget:
-            return bb
-        bb //= 2
-    return 8

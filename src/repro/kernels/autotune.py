@@ -41,6 +41,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.core import hw
 from repro.core.flops import prod
 from repro.core.packing import (BlockPlan, fused_chain_batch_tile,
                                 select_blocks_candidates)
@@ -169,9 +170,11 @@ def _median_time(fn: Callable[[], jax.Array], warmup: int = 1,
     return ts[len(ts) // 2]
 
 
-def _pow2_neighbors(v: int, B: int, lo: int = 8, hi: int = 1024) -> list[int]:
+def _pow2_neighbors(v: int, B: int, lo: int = hw.LANES,
+                    hi: int = 1024) -> list[int]:
     """The analytical pick and two octaves below it, clipped to
-    [lo, min(hi, B-ish)].  Never above ``v``: for the fused kernels ``v``
+    [lo, min(hi, B-ish)]; ``lo`` is one lane width, the smallest tile the
+    fused kernels run.  Never above ``v``: for the fused kernels ``v``
     is the LARGEST VMEM-feasible tile, so any larger candidate would win
     interpret-mode timing (no VMEM there) and persist a plan that busts
     VMEM on real hardware."""

@@ -37,10 +37,12 @@ weight residency at the cores' own itemsize (bf16 cores count 2 bytes).
 """
 from __future__ import annotations
 
+import functools
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.packing import pack_core
 from repro.core.quant import dequantize_cores, quantize_cores
@@ -113,6 +115,57 @@ def _chain_with_step_kernel(cores: Sequence[jax.Array], x: jax.Array,
     return state.reshape(M, B).T
 
 
+def _run_pallas(plan: TTExecutionPlan, x2: jax.Array,
+                cores: list[jax.Array], scales: list[jax.Array] | None,
+                interpret: bool | None) -> jax.Array:
+    """Dispatch a Pallas plan; ``scales`` selects the int8 kernels."""
+    ns, ms, ranks = plan.ns, plan.ms, plan.ranks
+    if plan.backend == "pallas_fused2":
+        dims2 = (ns[0], ns[1], ms[0], ms[1], ranks[1])
+        p2, p1 = pack_core(cores[1]), pack_core(cores[0])
+        if scales is not None:
+            return tt_fused2_int8_pallas(x2, p2, p1, [scales[1], scales[0]],
+                                         dims2, block_b=plan.block_b,
+                                         interpret=interpret)
+        return tt_fused2_pallas(x2, p2, p1, dims=dims2,
+                                block_b=plan.block_b, interpret=interpret)
+    if plan.backend == "pallas_fused":
+        if plan.block_b is None:
+            raise ValueError(
+                "malformed plan: pallas_fused without a batch tile — "
+                "re-resolve with kernels.plan.plan_tt_forward")
+        packed = [pack_core(G) for G in reversed(cores)]
+        if scales is not None:
+            return tt_fused_chain_int8_pallas(
+                x2, packed, list(reversed(scales)), (ns, ms, ranks),
+                block_b=plan.block_b, interpret=interpret)
+        return tt_fused_chain_pallas(x2, packed, (ns, ms, ranks),
+                                     block_b=plan.block_b,
+                                     interpret=interpret)
+    if plan.step_plans is None or len(plan.step_plans) != plan.d:
+        raise ValueError(
+            "malformed plan: pallas_step without per-step block plans "
+            "— re-resolve with kernels.plan.plan_tt_forward")
+    return _chain_with_step_kernel(cores, x2, interpret, plan.step_plans,
+                                   scales=scales)
+
+
+def _on_each_device(fn, *args):
+    """``fn(*args)``; under a multi-device mesh in context
+    (``jax.set_mesh``, as the serving scheduler sets it) the call runs
+    once per device on replicated operands instead.  GSPMD cannot
+    partition a Mosaic kernel, so the kernel must see whole operands; the
+    TT cores are replicated anyway, and each device computes the layer for
+    the full batch.  Inside a ``shard_map`` whose axes are all manual the
+    call already runs per device."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or \
+            set(mesh.manual_axes) == set(mesh.axis_names):
+        return fn(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
+
+
 def tt_forward(cores: Sequence[jax.Array], x: jax.Array,
                bias: jax.Array | None = None, backend: str = "auto",
                interpret: bool | None = None,
@@ -133,7 +186,6 @@ def tt_forward(cores: Sequence[jax.Array], x: jax.Array,
     scales), pre-quantized int8 ``cores`` require the matching ``scales``.
     Int8 cores passed without a weight mode imply ``weights='int8'``.
     """
-    d = len(cores)
     ns, ms, ranks = chain_dims(cores)
     Nc = 1
     for n in ns:
@@ -229,42 +281,10 @@ def tt_forward(cores: Sequence[jax.Array], x: jax.Array,
                          x2.astype(jnp.float32))
         else:
             y = tt_apply(cores, x2)
-    elif plan.backend == "pallas_fused2":
-        n1, n2 = ns
-        m1, m2 = ms
-        dims2 = (n1, n2, m1, m2, ranks[1])
-        if weights == "int8":
-            y = tt_fused2_int8_pallas(
-                x2, pack_core(qcores[1]), pack_core(qcores[0]),
-                [qscales[1], qscales[0]], dims2,
-                block_b=plan.block_b, interpret=interpret)
-        else:
-            y = tt_fused2_pallas(
-                x2, pack_core(cores[1]), pack_core(cores[0]),
-                dims=dims2, block_b=plan.block_b, interpret=interpret)
-    elif plan.backend == "pallas_fused":
-        if plan.block_b is None:
-            raise ValueError(
-                "malformed plan: pallas_fused without a batch tile — "
-                "re-resolve with kernels.plan.plan_tt_forward")
-        if weights == "int8":
-            packed = [pack_core(G) for G in reversed(qcores)]
-            y = tt_fused_chain_int8_pallas(
-                x2, packed, list(reversed(qscales)), (ns, ms, ranks),
-                block_b=plan.block_b, interpret=interpret)
-        else:
-            packed = [pack_core(G) for G in reversed(cores)]
-            y = tt_fused_chain_pallas(x2, packed, (ns, ms, ranks),
-                                      block_b=plan.block_b,
-                                      interpret=interpret)
-    elif plan.backend == "pallas_step":
-        if plan.step_plans is None or len(plan.step_plans) != d:
-            raise ValueError(
-                "malformed plan: pallas_step without per-step block plans "
-                "— re-resolve with kernels.plan.plan_tt_forward")
-        y = _chain_with_step_kernel(qcores if weights == "int8" else cores,
-                                    x2, interpret, plan.step_plans,
-                                    scales=qscales)
+    elif plan.backend in ("pallas_step", "pallas_fused2", "pallas_fused"):
+        y = _on_each_device(
+            functools.partial(_run_pallas, plan, interpret=interpret), x2,
+            qcores if weights == "int8" else list(cores), qscales)
     else:
         raise ValueError(
             f"plan resolved to unknown backend {plan.backend!r}")

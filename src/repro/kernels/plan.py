@@ -27,7 +27,7 @@ Three levels of API, outermost first:
 
 ``plan_tt_forward``
     The actual resolver: subsumes the old ``parse_backend_spec`` + auto
-    routing + ``select_blocks``/``chain_fits_vmem`` + autotune-cache
+    routing + ``select_blocks``/``fused_chain_batch_tile`` + autotune-cache
     lookup.  Every call increments ``PLAN_RESOLUTIONS`` so tests and the
     CI smoke can assert that serving performs ZERO re-planning.
 

@@ -181,8 +181,10 @@ def main(argv=None) -> dict:
                     help="CI mode: 2 trials, interrupted + resumed, "
                          "bit-determinism asserted")
     ap.add_argument("--compile-cache", default=None,
-                    help="persistent XLA compilation cache dir (also via "
-                         "$REPRO_COMPILE_CACHE): a resumed study re-jits "
+                    help="persistent XLA compilation cache dir (default "
+                         "<checkout>/.compile_cache; "
+                         "$JAX_COMPILATION_CACHE_DIR, when set, wins): a "
+                         "resumed study re-jits "
                          "none of the trial programs a previous process "
                          "already compiled")
     args = ap.parse_args(argv)

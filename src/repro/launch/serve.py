@@ -230,7 +230,8 @@ def simulate(model, params, args) -> dict:
         raise AssertionError(
             f"{replans} TT plan resolutions during the steady-state run — "
             "serving must execute build-time plans only")
-    return {"finished": finished, "tok_per_s": tok_s, "p50_s": p50,
+    return {"scheduler": sched, "finished": finished, "tok_per_s": tok_s,
+            "p50_s": p50,
             "p95_s": p95, "ttft_p50_s": ttft50, "ttft_p95_s": ttft95,
             "itl_p50_s": itl50, "itl_p95_s": itl95,
             "compile_s": compile_s, "replans": replans,
@@ -370,8 +371,7 @@ def first_token(model, params, args) -> dict:
            "steps": args.steps,
            "start_to_first_token_s": round(t_first, 4),
            "compile_cache": args.compile_cache,
-           "cache_entries": (cache_entries(args.compile_cache)
-                             if args.compile_cache else None)}
+           "cache_entries": cache_entries(args.compile_cache)}
     print("COLD_START " + json.dumps(out))
     return out
 
@@ -682,7 +682,10 @@ def main(argv=None) -> dict:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--tt", default=None)
     ap.add_argument("--tt-rank", type=int, default=16)
-    ap.add_argument("--tt-backend", default="xla")
+    ap.add_argument("--tt-backend", default="auto",
+                    choices=list(ttplan.BACKENDS),
+                    help="TT chain backend; auto runs the Pallas kernels "
+                         "(fused where VMEM admits the chain)")
     ap.add_argument("--tt-autotune", default="cached",
                     choices=["off", "cached", "measure"])
     ap.add_argument("--tt-weights", default="fp32",
@@ -760,9 +763,10 @@ def main(argv=None) -> dict:
                          "plan-compile-execute contract, DESIGN.md §10)")
     # durability (DESIGN.md §13)
     ap.add_argument("--compile-cache", default=None,
-                    help="persistent XLA compilation cache dir (also via "
-                         "$REPRO_COMPILE_CACHE); a restarted process "
-                         "reuses every compiled program")
+                    help="persistent XLA compilation cache dir (default "
+                         "<checkout>/.compile_cache; "
+                         "$JAX_COMPILATION_CACHE_DIR, when set, wins); a "
+                         "restarted process reuses every compiled program")
     ap.add_argument("--assert-cache-hits", action="store_true",
                     help="fail if this run adds any entry to "
                          "--compile-cache (CI warm-start smoke: the "
@@ -808,8 +812,8 @@ def main(argv=None) -> dict:
               f"({len(args.mesh_obj.devices.ravel())} devices)")
 
     cache_dir = enable_compile_cache(args.compile_cache)
-    args.compile_cache = cache_dir        # resolves $REPRO_COMPILE_CACHE
-    n_cache0 = cache_entries(cache_dir) if cache_dir else 0
+    args.compile_cache = cache_dir
+    n_cache0 = cache_entries(cache_dir)
 
     tt = None
     if args.tt:
@@ -849,15 +853,14 @@ def main(argv=None) -> dict:
         # for the other modes — exit 0 without a traceback
         print("\ninterrupted — exiting")
         return {"interrupted": True}
-    if cache_dir:
-        n1 = cache_entries(cache_dir)
-        print(f"compile cache {cache_dir}: {n_cache0} -> {n1} entries "
-              f"({n1 - n_cache0} new compilations persisted)")
-        if args.assert_cache_hits and (n1 != n_cache0 or n_cache0 == 0):
-            raise AssertionError(
-                f"warm start compiled {n1 - n_cache0} new programs "
-                f"(cache had {n_cache0} entries) — the persistent "
-                f"compilation cache must make a restart re-jit nothing")
+    n1 = cache_entries(cache_dir)
+    print(f"compile cache {cache_dir}: {n_cache0} -> {n1} entries "
+          f"({n1 - n_cache0} new compilations persisted)")
+    if args.assert_cache_hits and (n1 != n_cache0 or n_cache0 == 0):
+        raise AssertionError(
+            f"warm start compiled {n1 - n_cache0} new programs "
+            f"(cache had {n_cache0} entries) — the persistent "
+            f"compilation cache must make a restart re-jit nothing")
     return out
 
 

@@ -38,7 +38,8 @@ def make_mesh_from_devices():
         if n % m == 0 and m <= n:
             model = m
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def main(argv=None) -> dict:

@@ -25,21 +25,6 @@ from repro.distributed.sharding import shard_act
 from .layers import linear_spec, linear_apply, mlp_spec, mlp_apply
 from .spec import ParamSpec, is_spec, stack
 
-# jax >= 0.6 exposes shard_map at top level; older releases ship
-# jax.experimental.shard_map.  The replication-check kwarg was renamed
-# check_rep -> check_vma on its own schedule, so detect it by signature
-# rather than by where shard_map lives.
-try:
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-import inspect as _inspect
-
-_SHARD_MAP_NOCHECK = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else {"check_rep": False})
-
 
 def moe_spec(cfg: ModelConfig, dtype=jnp.float32) -> dict:
     m = cfg.moe
@@ -238,10 +223,10 @@ def moe_apply_ep(p, cfg: ModelConfig, x: jax.Array, backend="xla"
         return y.reshape(x_loc.shape)
 
     bspec = P(batch_axes, None, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(bspec, P(None, None), _experts_in_specs(cfg, mesh, case)),
-        out_specs=bspec, **_SHARD_MAP_NOCHECK)
+        out_specs=bspec, check_vma=False)
     y = fn(x, p["router"], p["experts"])
     if m.num_shared:
         y = y + mlp_apply(p["shared"], x.reshape(-1, d), backend

@@ -373,7 +373,17 @@ class Scheduler:
         additionally requires the block reservation to fit — admission by
         memory; preemption may evict lower-ranked slots), then run one
         masked decode step.  Returns the requests retired during this
-        call."""
+        call.
+
+        With a mesh the step runs with it in context (``jax.set_mesh``):
+        the TT layers find it there and run their Pallas kernels once per
+        device (``kernels.ops._on_each_device``)."""
+        if self.mesh is None:
+            return self._step()
+        with jax.set_mesh(self.mesh):
+            return self._step()
+
+    def _step(self) -> list[FinishedRequest]:
         done: list[FinishedRequest] = []
         self._expire(self._now(), done)
         self._apply_pending_resize()

@@ -13,9 +13,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.packing import (BlockPlan, chain_state_sizes,
-                                chain_weight_elems, fused_chain_batch_tile,
-                                pack_core)
+from repro.core import hw
+from repro.core.packing import (BlockPlan, fused_chain_batch_tile,
+                                fused_chain_vmem_bytes, pack_core)
 from repro.core.quant import (dequantize_cores, pack_core_int8,
                               quantize_core, quantize_cores)
 from repro.core.tt import make_plan, tt_apply, tt_init
@@ -185,17 +185,16 @@ def test_chain_fused_eligible_only_under_int8(monkeypatch):
     (step fallback, d launches) must fuse to ONE launch under int8
     residency — same chain, same batch, only the resident dtype changed."""
     plan, cores, x = _setup((8, 4, 4), (4, 4, 8), 4, 16)
-    sizes = chain_state_sizes(plan.ns, plan.ms, plan.ranks)
-    weights = chain_weight_elems(plan.ns, plan.ms, plan.ranks)
-    peak = max(a + b for a, b in zip(sizes, sizes[1:]))
-    # budget between (states + int8 weights) and (states + fp32 weights)
-    budget = peak * 8 * 4 * 2 + 2 * weights
+    # budget at which int8 cores fit at the smallest (one-lane) tile; fp32
+    # cores cost 8 more bytes per weight element there
+    budget = fused_chain_vmem_bytes(hw.LANES, plan.ns, plan.ms, plan.ranks,
+                                    weight_itemsize=1)
     assert fused_chain_batch_tile(plan.ns, plan.ms, plan.ranks,
                                   vmem_budget=budget,
                                   weight_itemsize=4) is None
     assert fused_chain_batch_tile(plan.ns, plan.ms, plan.ranks,
                                   vmem_budget=budget,
-                                  weight_itemsize=1) == 8
+                                  weight_itemsize=1) == hw.LANES
 
     import repro.kernels.plan as ttplan
     from repro.core.packing import chain_fit_report

@@ -2,9 +2,10 @@
 including the per-operand-itemsize (weights vs activations) fit model of
 DESIGN.md §8."""
 from repro.core import hw
-from repro.core.packing import (BlockPlan, chain_fits_vmem,
-                                chain_weight_elems, fused2_batch_tile,
-                                fused_chain_batch_tile, select_blocks)
+from repro.core.packing import (BlockPlan, chain_state_sizes,
+                                fused_chain_batch_tile,
+                                fused_chain_vmem_bytes, select_blocks,
+                                step_tiles)
 
 
 def test_select_blocks_respects_vmem_budget():
@@ -43,17 +44,38 @@ def test_bigger_budget_never_increases_traffic():
     assert large.traffic_bytes <= small.traffic_bytes
 
 
-def test_chain_fits_vmem():
-    assert chain_fits_vmem([1024, 1024])
-    assert not chain_fits_vmem([hw.VMEM_BUDGET_BYTES, hw.VMEM_BUDGET_BYTES])
+def test_step_tiles_are_tpu_aligned():
+    """The step kernel runs a BlockPlan as 2-D tiles whose lane dims are
+    whole 128-lane widths and whose rows suit int8 (32-row) tiles."""
+    tb, tk, tn = step_tiles(bm=8, bb=99, bn=8, rt=16, rt_1=1)
+    assert (tb, tk, tn) == (128, 128, 128)
+    assert step_tiles(512, 1024, 64, 16, 16) == (1024, 1024, 8192)
 
 
-def test_fused2_batch_tile_monotone():
-    t_small = fused2_batch_tile(N=4096, M=4096, mid=8192, weights=1 << 20)
-    t_big = fused2_batch_tile(N=256, M=256, mid=512, weights=1 << 10)
-    assert 8 <= t_small <= t_big <= 1024
-    need = 2 * 4 * (t_small * (4096 + 8192 + 4096)) + 4 * (1 << 20)
-    assert need <= hw.VMEM_BUDGET_BYTES or t_small == 8
+def test_fused_chain_vmem_bytes_grows_with_tile():
+    ns, ms, ranks = (8, 512), (1376, 8), (1, 16, 1)
+    sizes = chain_state_sizes(ns, ms, ranks)
+    small = fused_chain_vmem_bytes(128, ns, ms, ranks)
+    big = fused_chain_vmem_bytes(256, ns, ms, ranks)
+    # per-row cost: x/y tiles (double-buffered) and the state pair with
+    # its relayout copy, all at fp32
+    per_row = 2 * 4 * (sizes[0] + sizes[-1]) + 2 * 4 * max(
+        a + b for a, b in zip(sizes, sizes[1:]))
+    assert big - small == 128 * per_row
+
+
+def test_fused_chain_batch_tile_is_largest_fitting_lane_multiple():
+    ns, ms, ranks = (8, 512), (1376, 8), (1, 16, 1)
+    tile = fused_chain_batch_tile(ns, ms, ranks)
+    assert tile is not None and tile % hw.LANES == 0
+    assert fused_chain_vmem_bytes(tile, ns, ms, ranks) <= \
+        hw.VMEM_BUDGET_BYTES
+    assert tile == 1024 or fused_chain_vmem_bytes(
+        2 * tile, ns, ms, ranks) > hw.VMEM_BUDGET_BYTES
+    # below one lane width nothing fits: the chain is step-fallback
+    budget = fused_chain_vmem_bytes(hw.LANES, ns, ms, ranks) - 1
+    assert fused_chain_batch_tile(ns, ms, ranks,
+                                  vmem_budget=budget) is None
 
 
 # ---------------------------------------------------------------------------
@@ -61,41 +83,19 @@ def test_fused2_batch_tile_monotone():
 # eligibility set and never shrink a tile
 # ---------------------------------------------------------------------------
 
-def test_chain_fits_vmem_weight_itemsize():
-    """Weights priced per their own dtype: a weight block that busts the
-    budget at 4 B/elem fits at 1 B/elem with identical states."""
-    w = hw.VMEM_BUDGET_BYTES // 3           # 4w > budget > 1w + states
-    states = [1024, 1024]
-    assert not chain_fits_vmem(states, weight_elems=w, weight_itemsize=4)
-    assert chain_fits_vmem(states, weight_elems=w, weight_itemsize=1)
-    # default (None) keeps the old single-itemsize behavior
-    assert chain_fits_vmem(states, weight_elems=w) == \
-        chain_fits_vmem(states, weight_elems=w, weight_itemsize=4)
-
-
 def test_fused_chain_tile_grows_under_int8_residency():
     """The dtype-aware fit test: int8 weights never yield a smaller tile,
     and on a weight-dominated chain they admit a strictly larger one (or
     flip None → fused-eligible)."""
-    ns, ms, ranks = (4, 32, 32), (32, 32, 4), (1, 128, 128, 1)
+    ns, ms, ranks = (2, 4096), (4096, 2), (1, 512, 1)
     t_fp = fused_chain_batch_tile(ns, ms, ranks, weight_itemsize=4)
     t_bf = fused_chain_batch_tile(ns, ms, ranks, weight_itemsize=2)
     t_q = fused_chain_batch_tile(ns, ms, ranks, weight_itemsize=1)
-    assert t_fp is None and t_bf is None      # 67/34 MB of weights: no fit
-    assert t_q == 8                           # 16.8 MB int8: fused
+    assert t_fp is None and t_bf is None      # 34/17 MB of cores, twice
+    assert t_q == hw.LANES                    # 8.4 MB int8: fused
     # a smaller chain: tile is monotone non-decreasing as weights shrink
     ns2, ms2, ranks2 = (8, 8, 8), (8, 8, 8), (1, 8, 8, 1)
     tiles = [fused_chain_batch_tile(ns2, ms2, ranks2, weight_itemsize=w)
              for w in (4, 2, 1)]
     assert all(t is not None for t in tiles)
     assert tiles[0] <= tiles[1] <= tiles[2]
-
-
-def test_fused2_tile_weight_itemsize():
-    N = M = 2048
-    mid, w = 4096, 6 << 20
-    t4 = fused2_batch_tile(N, M, mid, w, weight_itemsize=4)
-    t1 = fused2_batch_tile(N, M, mid, w, weight_itemsize=1)
-    assert t1 >= t4
-    need = 2 * 4 * (t1 * (N + mid + M)) + 1 * w
-    assert need <= hw.VMEM_BUDGET_BYTES or t1 == 8

@@ -30,7 +30,7 @@ KEY = jax.random.PRNGKey(0)
 
 # d=3 chain whose fp32 packed cores alone bust the 32 MiB VMEM budget
 # (bench_quant's showcase): step-fallback in fp32, fused under int8
-BIG = ((32, 32, 4), (4, 32, 32), 128)          # (ms, ns, rank)
+BIG = ((256, 2, 16), (4, 4, 64), 128)          # (ms, ns, rank)
 SMALL3 = ((8, 4, 4), (4, 4, 8), 4)
 
 

@@ -47,12 +47,8 @@ def test_parser_on_real_lowered_hlo():
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        shard_map = jax.shard_map                  # jax >= 0.6
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
     mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
-    f = shard_map(lambda a: jax.lax.psum(a, "x"), mesh=mesh,
+    f = jax.shard_map(lambda a: jax.lax.psum(a, "x"), mesh=mesh,
                   in_specs=P(), out_specs=P())
     txt = jax.jit(f).lower(jnp.ones((8, 8))).compile().as_text()
     assert "all-reduce" in txt

@@ -74,7 +74,8 @@ from repro.training.optimizer import OptConfig
 from repro.training.train_loop import TrainConfig, make_train_step
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
 cfg = get_config("qwen3_32b", "smoke")
 model = build(cfg)
