@@ -1,0 +1,11 @@
+"""95th percentile of every inter-token gap that ended in the window,
+over all requests."""
+import numpy as np
+
+SOURCE = "host_clock"
+UNIT = "ms"
+
+
+def read(w):
+    g = w.gaps_s()
+    return float(np.percentile(g, 95)) * 1e3 if g else None
