@@ -103,6 +103,18 @@ def _print_pool_stats(sched) -> None:
               f"({st['prefill_tokens_skipped']} prefill tokens skipped)")
     else:
         print()
+    steps, host = st["host_steps"], st["host_s"]
+    if steps:
+        split = ", ".join(f"{k} {v / steps * 1e3:.2f}"
+                          for k, v in host.items() if k != "step")
+        print(f"host phases, ms per step: step "
+              f"{host['step'] / steps * 1e3:.2f} ({split}); longest step "
+              f"{st['host_max_s']['step'] * 1e3:.1f} ms")
+    n = st["first_tokens"]
+    if n:
+        print(f"first token, mean of {n}: queue wait "
+              f"{st['ttft_queue_s'] / n * 1e3:.1f} ms, prefill "
+              f"{st['ttft_prefill_s'] / n * 1e3:.1f} ms")
 
 
 def simulate(model, params, args) -> dict:

@@ -159,14 +159,16 @@ class Model:
         return rmsnorm_apply(params["enc_norm"], x, cfg.norm_eps)
 
     def _logits(self, params, x) -> jax.Array:
+        """Final norm and head, under the ``lm_head`` scope."""
         cfg = self.cfg
-        x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-        if cfg.tie_embeddings:
-            logits = x @ params["embed"]["table"].T
-        else:
-            logits = linear_apply(params["lm_head"], x, self.plan_book)
-        return shard_act(logits.astype(jnp.float32),
-                         ("act_batch", None, "act_vocab"))
+        with jax.named_scope("lm_head"):
+            x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
+            if cfg.tie_embeddings:
+                logits = x @ params["embed"]["table"].T
+            else:
+                logits = linear_apply(params["lm_head"], x, self.plan_book)
+            return shard_act(logits.astype(jnp.float32),
+                             ("act_batch", None, "act_vocab"))
 
     # ------------------------------------------------------------------ train
     def loss(self, params, batch, remat: bool = True) -> jax.Array:

@@ -9,6 +9,7 @@ hybrids (jamba 1:7 attn:mamba, gemma3 5:1 local:global) use longer periods.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Sequence
@@ -170,6 +171,32 @@ def _ring_cache(k, W, true_len):
     return jnp.where(valid.reshape(vshape), g, 0)
 
 
+# ---------------------------------------------------------------------------
+# Named scopes: every family's blocks put the same names into the HLO
+# metadata (``op_name``), where the benchmark's device-trace readers find
+# them (DESIGN.md §16).  A scope changes metadata only, never the program.
+# ---------------------------------------------------------------------------
+
+def _attn_scope(bd: BlockDef, name: str):
+    """``jax.named_scope(name)`` around an attention mixer's work; SSM
+    mixers stay outside every attention scope."""
+    if bd.mixer in ("gqa", "mla"):
+        return jax.named_scope(name)
+    return contextlib.nullcontext()
+
+
+def _ffn(p, cfg: ModelConfig, bd: BlockDef, x, backend):
+    """The block's FFN (pre-norm, dense MLP or MoE, residual) under the
+    ``ffn`` scope."""
+    if bd.ffn == "none":
+        return x
+    with jax.named_scope("ffn"):
+        h = rmsnorm_apply(p["ln2"], x, cfg.norm_eps)
+        if bd.ffn == "moe":
+            return x + moe_apply(p["ffn"], cfg, h, backend)
+        return x + mlp_apply(p["ffn"], h, backend)
+
+
 def block_fwd(p, cfg: ModelConfig, bd: BlockDef, x, positions, *,
               enc_out=None, want_cache: bool, T_cache: int = 0,
               plans=None, true_len=None):
@@ -226,12 +253,7 @@ def block_fwd(p, cfg: ModelConfig, bd: BlockDef, x, positions, *,
                            *_enc_kv(p, cfg, bd, enc_out, cache, want_cache,
                                     backend),
                            backend=backend)
-    if bd.ffn != "none":
-        h = rmsnorm_apply(p["ln2"], x, cfg.norm_eps)
-        if bd.ffn == "moe":
-            x = x + moe_apply(p["ffn"], cfg, h, backend)
-        else:
-            x = x + mlp_apply(p["ffn"], h, backend)
+    x = _ffn(p, cfg, bd, x, backend)
     x = shard_act(x, ("act_batch", "act_seq", "act_embed"))
     return x, (cache if want_cache else None)
 
@@ -260,47 +282,43 @@ def block_decode(p, cfg: ModelConfig, bd: BlockDef, x, cache: dict, pos,
     backend = plans if plans is not None else cfg.tt.backend_spec
     h = rmsnorm_apply(p["ln1"], x, cfg.norm_eps)
     new_cache = dict(cache)
-    if bd.mixer == "gqa":
-        if paged is not None:
-            bt, pact = paged
-            y, nk, nv = gqa_decode_attn_paged(
-                p["attn"], cfg, h, cache["k"], cache["v"], bt, pos, pact,
-                window=bd.window, theta=bd.theta, backend=backend)
+    with _attn_scope(bd, "decode_attention"):
+        if bd.mixer == "gqa":
+            if paged is not None:
+                bt, pact = paged
+                y, nk, nv = gqa_decode_attn_paged(
+                    p["attn"], cfg, h, cache["k"], cache["v"], bt, pos, pact,
+                    window=bd.window, theta=bd.theta, backend=backend)
+            else:
+                y, nk, nv = gqa_decode_attn(p["attn"], cfg, h, cache["k"],
+                                            cache["v"], pos, window=bd.window,
+                                            theta=bd.theta, backend=backend,
+                                            active=active)
+            new_cache.update(k=nk, v=nv)
+        elif bd.mixer == "mla":
+            if paged is not None:
+                bt, pact = paged
+                y, nckv, nkr = mla_decode_attn_paged(
+                    p["attn"], cfg, h, cache["ckv"], cache["krope"], bt, pos,
+                    pact, backend=backend)
+            else:
+                y, nckv, nkr = mla_decode_attn(p["attn"], cfg, h, cache["ckv"],
+                                               cache["krope"], pos,
+                                               backend=backend, active=active)
+            new_cache.update(ckv=nckv, krope=nkr)
         else:
-            y, nk, nv = gqa_decode_attn(p["attn"], cfg, h, cache["k"],
-                                        cache["v"], pos, window=bd.window,
-                                        theta=bd.theta, backend=backend,
-                                        active=active)
-        new_cache.update(k=nk, v=nv)
-    elif bd.mixer == "mla":
-        if paged is not None:
-            bt, pact = paged
-            y, nckv, nkr = mla_decode_attn_paged(
-                p["attn"], cfg, h, cache["ckv"], cache["krope"], bt, pos,
-                pact, backend=backend)
-        else:
-            y, nckv, nkr = mla_decode_attn(p["attn"], cfg, h, cache["ckv"],
-                                           cache["krope"], pos,
-                                           backend=backend, active=active)
-        new_cache.update(ckv=nckv, krope=nkr)
-    else:
-        y, st, cv = ssm_decode(p["ssm"], cfg, h, cache["state"],
-                               cache["conv"], backend)
-        if active is not None:
-            st = jnp.where(active[:, None, None, None], st, cache["state"])
-            cv = jnp.where(active[:, None, None], cv, cache["conv"])
-        new_cache.update(state=st, conv=cv)
+            y, st, cv = ssm_decode(p["ssm"], cfg, h, cache["state"],
+                                   cache["conv"], backend)
+            if active is not None:
+                st = jnp.where(active[:, None, None, None], st, cache["state"])
+                cv = jnp.where(active[:, None, None], cv, cache["conv"])
+            new_cache.update(state=st, conv=cv)
     x = x + y
     if bd.cross:
         h = rmsnorm_apply(p["ln_x"], x, cfg.norm_eps)
         x = x + cross_attn(p["xattn"], cfg, h, cache["xk"], cache["xv"],
                            backend=backend)
-    if bd.ffn != "none":
-        h = rmsnorm_apply(p["ln2"], x, cfg.norm_eps)
-        if bd.ffn == "moe":
-            x = x + moe_apply(p["ffn"], cfg, h, backend)
-        else:
-            x = x + mlp_apply(p["ffn"], h, backend)
+    x = _ffn(p, cfg, bd, x, backend)
     return x, new_cache
 
 
@@ -386,12 +404,7 @@ def block_resume(p, cfg: ModelConfig, bd: BlockDef, x, cache: dict, src_b,
             f"mixer {bd.mixer!r} (window={bd.window}) does not support "
             "prefix-resume prefill")
     x = x + y
-    if bd.ffn != "none":
-        h = rmsnorm_apply(p["ln2"], x, cfg.norm_eps)
-        if bd.ffn == "moe":
-            x = x + moe_apply(p["ffn"], cfg, h, backend)
-        else:
-            x = x + mlp_apply(p["ffn"], h, backend)
+    x = _ffn(p, cfg, bd, x, backend)
     return x, new_cache
 
 
@@ -454,88 +467,85 @@ def block_chunk(p, cfg: ModelConfig, bd: BlockDef, x, cache: dict, slot,
         return leaf.at[slot].set(
             jnp.where(active, new_row.astype(leaf.dtype), old))
 
-    if bd.mixer == "gqa" and not bd.window:
-        if table is not None:
-            dk = _resume_dense(cache["k"], table, C)
-            dv = _resume_dense(cache["v"], table, C)
-            y, dk, dv = gqa_chunk_attn(p["attn"], cfg, h, dk, dv, start,
-                                       theta=bd.theta, backend=backend)
-            new_cache["k"] = _resume_scatter(cache["k"], table, dk)
-            new_cache["v"] = _resume_scatter(cache["v"], table, dv)
-        else:
-            T = cache["k"].shape[1]
-            pad = lambda r: jnp.concatenate(
-                [r, jnp.zeros((1, C) + r.shape[2:], r.dtype)], axis=1)
-            dk, dv = pad(_row(cache["k"])), pad(_row(cache["v"]))
-            y, dk, dv = gqa_chunk_attn(p["attn"], cfg, h, dk, dv, start,
-                                       theta=bd.theta, backend=backend)
-            new_cache["k"] = _put(cache["k"], dk[0, :T])
-            new_cache["v"] = _put(cache["v"], dv[0, :T])
-    elif bd.mixer == "gqa":
-        if table is not None:
-            blk = cache["k"].shape[1]
-            W = min(bd.window, table.shape[0] * blk)
-            nblk = -(-W // blk)
+    with _attn_scope(bd, "chunk_attention"):
+        if bd.mixer == "gqa" and not bd.window:
+            if table is not None:
+                dk = _resume_dense(cache["k"], table, C)
+                dv = _resume_dense(cache["v"], table, C)
+                y, dk, dv = gqa_chunk_attn(p["attn"], cfg, h, dk, dv, start,
+                                           theta=bd.theta, backend=backend)
+                new_cache["k"] = _resume_scatter(cache["k"], table, dk)
+                new_cache["v"] = _resume_scatter(cache["v"], table, dv)
+            else:
+                T = cache["k"].shape[1]
+                pad = lambda r: jnp.concatenate(
+                    [r, jnp.zeros((1, C) + r.shape[2:], r.dtype)], axis=1)
+                dk, dv = pad(_row(cache["k"])), pad(_row(cache["v"]))
+                y, dk, dv = gqa_chunk_attn(p["attn"], cfg, h, dk, dv, start,
+                                           theta=bd.theta, backend=backend)
+                new_cache["k"] = _put(cache["k"], dk[0, :T])
+                new_cache["v"] = _put(cache["v"], dv[0, :T])
+        elif bd.mixer == "gqa":
+            if table is not None:
+                blk = cache["k"].shape[1]
+                W = min(bd.window, table.shape[0] * blk)
+                nblk = -(-W // blk)
 
-            def _gather_ring(arena):
-                g = arena[table[:nblk]].reshape(
-                    1, nblk * blk, *arena.shape[2:])
-                return g, g[:, :W]
+                def _gather_ring(arena):
+                    g = arena[table[:nblk]].reshape(
+                        1, nblk * blk, *arena.shape[2:])
+                    return g, g[:, :W]
 
-            gk, rk = _gather_ring(cache["k"])
-            gv, rv = _gather_ring(cache["v"])
-            y, nk, nv = gqa_chunk_attn_ring(p["attn"], cfg, h, rk, rv,
-                                            start, true_len, theta=bd.theta,
-                                            backend=backend)
+                gk, rk = _gather_ring(cache["k"])
+                gv, rv = _gather_ring(cache["v"])
+                y, nk, nv = gqa_chunk_attn_ring(
+                    p["attn"], cfg, h, rk, rv, start, true_len,
+                    theta=bd.theta, backend=backend)
 
-            def _scatter_ring(arena, g, new_ring):
-                merged = g.at[:, :W].set(new_ring.astype(g.dtype))
-                blocks = merged[0].reshape(nblk, blk, *arena.shape[2:])
-                return arena.at[table[:nblk]].set(blocks)
+                def _scatter_ring(arena, g, new_ring):
+                    merged = g.at[:, :W].set(new_ring.astype(g.dtype))
+                    blocks = merged[0].reshape(nblk, blk, *arena.shape[2:])
+                    return arena.at[table[:nblk]].set(blocks)
 
-            new_cache["k"] = _scatter_ring(cache["k"], gk, nk)
-            new_cache["v"] = _scatter_ring(cache["v"], gv, nv)
-        else:
-            rk, rv = _row(cache["k"]), _row(cache["v"])
-            y, nk, nv = gqa_chunk_attn_ring(p["attn"], cfg, h, rk, rv,
-                                            start, true_len, theta=bd.theta,
-                                            backend=backend)
-            new_cache["k"] = _put(cache["k"], nk[0])
-            new_cache["v"] = _put(cache["v"], nv[0])
-    elif bd.mixer == "mla":
-        if table is not None:
-            dckv = _resume_dense(cache["ckv"], table, C)
-            dkr = _resume_dense(cache["krope"], table, C)
-            y, dckv, dkr = mla_chunk_attn(p["attn"], cfg, h, dckv, dkr,
-                                          start, backend=backend)
-            new_cache["ckv"] = _resume_scatter(cache["ckv"], table, dckv)
-            new_cache["krope"] = _resume_scatter(cache["krope"], table, dkr)
-        else:
-            T = cache["ckv"].shape[1]
-            pad = lambda r: jnp.concatenate(
-                [r, jnp.zeros((1, C) + r.shape[2:], r.dtype)], axis=1)
-            dckv = pad(_row(cache["ckv"]))
-            dkr = pad(_row(cache["krope"]))
-            y, dckv, dkr = mla_chunk_attn(p["attn"], cfg, h, dckv, dkr,
-                                          start, backend=backend)
-            new_cache["ckv"] = _put(cache["ckv"], dckv[0, :T])
-            new_cache["krope"] = _put(cache["krope"], dkr[0, :T])
-    else:  # ssm — slot-indexed state in both layouts
-        st, cv = _row(cache["state"]), _row(cache["conv"])
-        fresh = start == 0
-        st = jnp.where(fresh, jnp.zeros_like(st), st)
-        cv = jnp.where(fresh, jnp.zeros_like(cv), cv)
-        y, st2, tail = ssm_forward(p["ssm"], cfg, h, backend,
-                                   true_len=true_len, s0=st, conv_hist=cv)
-        new_cache["state"] = _put(cache["state"], st2[0])
-        new_cache["conv"] = _put(cache["conv"], tail[0])
+                new_cache["k"] = _scatter_ring(cache["k"], gk, nk)
+                new_cache["v"] = _scatter_ring(cache["v"], gv, nv)
+            else:
+                rk, rv = _row(cache["k"]), _row(cache["v"])
+                y, nk, nv = gqa_chunk_attn_ring(
+                    p["attn"], cfg, h, rk, rv, start, true_len,
+                    theta=bd.theta, backend=backend)
+                new_cache["k"] = _put(cache["k"], nk[0])
+                new_cache["v"] = _put(cache["v"], nv[0])
+        elif bd.mixer == "mla":
+            if table is not None:
+                dckv = _resume_dense(cache["ckv"], table, C)
+                dkr = _resume_dense(cache["krope"], table, C)
+                y, dckv, dkr = mla_chunk_attn(p["attn"], cfg, h, dckv, dkr,
+                                              start, backend=backend)
+                new_cache["ckv"] = _resume_scatter(cache["ckv"], table, dckv)
+                new_cache["krope"] = _resume_scatter(cache["krope"], table,
+                                                     dkr)
+            else:
+                T = cache["ckv"].shape[1]
+                pad = lambda r: jnp.concatenate(
+                    [r, jnp.zeros((1, C) + r.shape[2:], r.dtype)], axis=1)
+                dckv = pad(_row(cache["ckv"]))
+                dkr = pad(_row(cache["krope"]))
+                y, dckv, dkr = mla_chunk_attn(p["attn"], cfg, h, dckv, dkr,
+                                              start, backend=backend)
+                new_cache["ckv"] = _put(cache["ckv"], dckv[0, :T])
+                new_cache["krope"] = _put(cache["krope"], dkr[0, :T])
+        else:  # ssm — slot-indexed state in both layouts
+            st, cv = _row(cache["state"]), _row(cache["conv"])
+            fresh = start == 0
+            st = jnp.where(fresh, jnp.zeros_like(st), st)
+            cv = jnp.where(fresh, jnp.zeros_like(cv), cv)
+            y, st2, tail = ssm_forward(p["ssm"], cfg, h, backend,
+                                       true_len=true_len, s0=st, conv_hist=cv)
+            new_cache["state"] = _put(cache["state"], st2[0])
+            new_cache["conv"] = _put(cache["conv"], tail[0])
     x = x + y
-    if bd.ffn != "none":
-        h = rmsnorm_apply(p["ln2"], x, cfg.norm_eps)
-        if bd.ffn == "moe":
-            x = x + moe_apply(p["ffn"], cfg, h, backend)
-        else:
-            x = x + mlp_apply(p["ffn"], h, backend)
+    x = _ffn(p, cfg, bd, x, backend)
     return x, new_cache
 
 

@@ -148,6 +148,7 @@ class _Slot:
     logprobs: list[float] = dataclasses.field(default_factory=list)
     last_tok: int = 0
     first_token_time: float | None = None
+    admit_time: float | None = None       # clock at the last admission
     # chunked-prefill state machine: ``prefill_pos`` is None once the
     # prompt is fully prefilled (slot is decoding); while prefilling it
     # counts prompt tokens already processed.  ``prefill_toks`` is the
@@ -158,6 +159,33 @@ class _Slot:
         default=None, repr=False)
     prefill_table: np.ndarray | None = dataclasses.field(
         default=None, repr=False)
+
+
+class _Phase:
+    """One host phase of ``Scheduler.step`` (DESIGN.md §16): a profiler
+    span ``sched.<name>``, recorded only while a trace is active, and the
+    phase's time on the scheduler's clock, summed into ``host_s[name]``
+    with the longest single occurrence in ``host_max_s[name]``.  Phases
+    nest: every wait for the device's tokens is a ``sync`` inside the
+    phase that waits, so ``host_s["step"] - host_s["sync"]`` is the
+    host's own time."""
+    __slots__ = ("sched", "name", "span", "t0")
+
+    def __init__(self, sched: "Scheduler", name: str, span=None):
+        self.sched, self.name = sched, name
+        self.span = span or jax.profiler.TraceAnnotation(f"sched.{name}")
+
+    def __enter__(self):
+        self.span.__enter__()
+        self.t0 = self.sched._now()
+
+    def __exit__(self, *exc):
+        s = self.sched
+        dt = s._now() - self.t0
+        self.span.__exit__(*exc)
+        s.host_s[self.name] = s.host_s.get(self.name, 0.0) + dt
+        if dt > s.host_max_s.get(self.name, 0.0):
+            s.host_max_s[self.name] = dt
 
 
 class Scheduler:
@@ -248,6 +276,13 @@ class Scheduler:
         self.expired = 0                  # requests retired past deadline
         self._target_slots: int | None = None   # pending slot shrink
         self.hold_admissions = False      # fault/SLO gate: skip admission
+        # host phases of step() (``_Phase``) and the first-token split
+        self.host_s: dict[str, float] = {}      # summed seconds per phase
+        self.host_max_s: dict[str, float] = {}  # longest single occurrence
+        self.host_steps = 0               # step() calls
+        self.first_tokens = 0             # first tokens emitted
+        self.ttft_queue_s = 0.0           # submit -> admission, summed
+        self.ttft_prefill_s = 0.0         # admission -> first token, summed
         # shared across Scheduler instances of the same model: a server
         # creating one Scheduler per batch must not recompile the pick
         self._pick = model._jit_get("pick", self._build_pick)
@@ -330,7 +365,13 @@ class Scheduler:
         out = {"tokens_out": self.tokens_out, "steps_run": self.steps_run,
                "kv_pool_bytes": self.kv_pool_bytes(),
                "preemptions": self.preemptions,
-               "cancelled": self.cancelled, "expired": self.expired}
+               "cancelled": self.cancelled, "expired": self.expired,
+               "host_s": dict(self.host_s),
+               "host_max_s": dict(self.host_max_s),
+               "host_steps": self.host_steps,
+               "first_tokens": self.first_tokens,
+               "ttft_queue_s": self.ttft_queue_s,
+               "ttft_prefill_s": self.ttft_prefill_s}
         if self.chunk_prefill:
             out.update(chunk_size=self.chunk_size,
                        prefill_budget=self.prefill_budget,
@@ -362,6 +403,9 @@ class Scheduler:
         self.tokens_out = self.steps_run = 0
         self.preemptions = self.cancelled = self.expired = 0
         self.prefill_chunks = 0
+        self.host_s, self.host_max_s = {}, {}
+        self.host_steps = self.first_tokens = 0
+        self.ttft_queue_s = self.ttft_prefill_s = 0.0
         if self.paged:
             self.block_hwm = self.allocator.in_use
             self.prefix_hit_tokens = self.prefix_prompt_tokens = 0
@@ -385,21 +429,26 @@ class Scheduler:
 
     def _step(self) -> list[FinishedRequest]:
         done: list[FinishedRequest] = []
-        self._expire(self._now(), done)
-        self._apply_pending_resize()
-        if not self.hold_admissions:
-            self._admit_phase(done)
-        if self.num_active:
-            if self.chunk_prefill and any(
-                    s is not None and s.prefill_pos is not None
-                    for s in self.slots):
-                self._mixed_once(done)
-            else:
-                self._decode_once(done)
-        # retirements this step may have been the last thing a deferred
-        # shrink was waiting on — land it now, not one step later
-        self._apply_pending_resize()
-        self.finished.extend(done)
+        self.host_steps += 1
+        with _Phase(self, "step", jax.profiler.StepTraceAnnotation(
+                "sched.step", step_num=self.host_steps)):
+            with _Phase(self, "admit"):
+                self._expire(self._now(), done)
+                self._apply_pending_resize()
+                if not self.hold_admissions:
+                    self._admit_phase(done)
+            if self.num_active:
+                if self.chunk_prefill and any(
+                        s is not None and s.prefill_pos is not None
+                        for s in self.slots):
+                    self._mixed_once(done)
+                else:
+                    self._decode_once(done)
+            # retirements this step may have been the last thing a
+            # deferred shrink was waiting on — land it now, not one step
+            # later
+            self._apply_pending_resize()
+            self.finished.extend(done)
         return done
 
     def run(self) -> dict[int, FinishedRequest]:
@@ -632,7 +681,8 @@ class Scheduler:
             self._gather_logits(logits_row[None]), keys,
             jnp.asarray([slot.temperature], jnp.float32),
             jnp.asarray([slot.top_k], jnp.int32))
-        return int(tok[0]), float(lp[0])
+        with _Phase(self, "sync"):
+            return int(tok[0]), float(lp[0])
 
     # ------------------------------------------------------------ pool build
     def _ensure_pool(self, row_cache: dict) -> None:
@@ -738,7 +788,7 @@ class Scheduler:
                   submit_time=q.submit_time,
                   temperature=float(req.temperature),
                   top_k=int(req.top_k), priority=int(req.priority),
-                  deadline=q.deadline)
+                  deadline=q.deadline, admit_time=self._now())
         if q.resume is not None:          # continue the interrupted stream
             s.tokens = list(q.resume.tokens)
             s.logprobs = list(q.resume.logprobs)
@@ -757,7 +807,11 @@ class Scheduler:
         slot.last_tok = tok
         self.tokens_out += 1
         if slot.first_token_time is None:
-            slot.first_token_time = self._now()
+            now = self._now()
+            slot.first_token_time = now
+            self.first_tokens += 1
+            self.ttft_queue_s += slot.admit_time - slot.submit_time
+            self.ttft_prefill_s += now - slot.admit_time
         cb = slot.req.on_token
         if cb is not None:
             cb(slot.uid, len(slot.tokens) - 1, tok, lp)
@@ -765,8 +819,8 @@ class Scheduler:
     def _admit_dense(self, q: _Queued, slot_idx: int,
                      done: list[FinishedRequest]) -> None:
         inputs, _ = self._admit_inputs(q)
-        logits, row_cache = self._row_prefill(inputs)
         slot = self._start_slot(q)
+        logits, row_cache = self._row_prefill(inputs)
         tok, lp = self._pick_one(logits[0, -1], slot)
         self._emit(slot, tok, lp)
         if self._finished_reason(slot):
@@ -1139,6 +1193,7 @@ class Scheduler:
                        "logprobs": list(s.logprobs), "last_tok": s.last_tok,
                        "key": arr(s.key),
                        "first_token_time": s.first_token_time,
+                       "admit_time": s.admit_time,
                        "prefill_pos": s.prefill_pos} for s in self.slots],
             "finished": [{"uid": f.uid, "tokens": np.asarray(f.tokens),
                           "logprobs": np.asarray(f.logprobs),
@@ -1251,6 +1306,7 @@ class Scheduler:
                 logprobs=[float(x) for x in d["logprobs"]],
                 last_tok=int(d["last_tok"]),
                 first_token_time=t_of(d.get("first_token_time")),
+                admit_time=t_of(d.get("admit_time", d["submit_time"])),
                 prefill_pos=(None if d.get("prefill_pos") is None
                              else int(d["prefill_pos"]))))
         sched.slots = slots
@@ -1324,31 +1380,37 @@ class Scheduler:
         prefilling neither consume PRNG splits nor receive tokens."""
         decoding = [s if s is not None and s.prefill_pos is None else None
                     for s in self.slots]
-        if any(s is not None and s.temperature > 0.0 for s in decoding):
-            keys = jnp.stack([
-                self._next_key(s) if s is not None and s.temperature > 0.0
-                else jnp.zeros((2,), jnp.uint32)
-                for s in decoding])
-        else:                             # all greedy: no splits consumed
-            keys = jnp.zeros((self.num_slots, 2), jnp.uint32)
-        tok, lp = self._pick(self._gather_logits(logits[:, 0, :]), keys,
-                             jnp.asarray(temps), jnp.asarray(topk))
-        tok, lp = np.asarray(tok), np.asarray(lp)
+        with _Phase(self, "pick"):
+            if any(s is not None and s.temperature > 0.0 for s in decoding):
+                keys = jnp.stack([
+                    self._next_key(s) if s is not None and s.temperature > 0.0
+                    else jnp.zeros((2,), jnp.uint32)
+                    for s in decoding])
+            else:                         # all greedy: no splits consumed
+                keys = jnp.zeros((self.num_slots, 2), jnp.uint32)
+            tok, lp = self._pick(self._gather_logits(logits[:, 0, :]), keys,
+                                 jnp.asarray(temps), jnp.asarray(topk))
+        with _Phase(self, "sync"):
+            tok, lp = np.asarray(tok), np.asarray(lp)
         self.steps_run += 1
-        for i, s in enumerate(decoding):
-            if s is None:
-                continue
-            self._emit(s, int(tok[i]), float(lp[i]))
-            if self._finished_reason(s):
-                done.append(self._retire(s))
-                if self.paged:
-                    self._release_blocks(i)
-                self.slots[i] = None
+        with _Phase(self, "emit"):
+            for i, s in enumerate(decoding):
+                if s is None:
+                    continue
+                self._emit(s, int(tok[i]), float(lp[i]))
+                if self._finished_reason(s):
+                    done.append(self._retire(s))
+                    if self.paged:
+                        self._release_blocks(i)
+                    self.slots[i] = None
 
     def _decode_once(self, done: list[FinishedRequest]) -> None:
-        toks, active, temps, topk = self._decode_arrays()
-        logits, self.cache = self.model.jitted_decode_step_masked(self.mesh)(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(active))
+        with _Phase(self, "inputs"):
+            toks, active, temps, topk = self._decode_arrays()
+            toks, active = jnp.asarray(toks), jnp.asarray(active)
+        with _Phase(self, "dispatch"):
+            logits, self.cache = self.model.jitted_decode_step_masked(
+                self.mesh)(self.params, self.cache, toks, active)
         self._finish_decode(logits, temps, topk, done)
 
     def _mixed_once(self, done: list[FinishedRequest]) -> None:
@@ -1358,38 +1420,40 @@ class Scheduler:
         per (K, C) shape, so the zero-replan contract holds under
         chunked prefill."""
         K, C = self.chunk_lanes, self.chunk_size
-        toks, active, temps, topk = self._decode_arrays()
-        pref = sorted(
-            (self._srank(s), i) for i, s in enumerate(self.slots)
-            if s is not None and s.prefill_pos is not None)
-        lanes: list[tuple[int, int, int]] = []
-        ck_tok = np.zeros((K, C), np.int32)
-        ck_slot = np.zeros((K,), np.int32)
-        ck_start = np.zeros((K,), np.int32)
-        ck_true = np.ones((K,), np.int32)   # 1 keeps unused lanes in-range
-        ck_active = np.zeros((K,), bool)
-        ck_tables = (np.full((K, self.max_blocks), self.num_blocks,
-                             np.int32) if self.paged else None)
-        for j, (_, i) in enumerate(pref[:K]):
-            s = self.slots[i]
-            start = s.prefill_pos
-            take = min(C, len(s.prefill_toks) - start)
-            ck_tok[j, :take] = s.prefill_toks[start:start + take]
-            ck_slot[j] = i
-            ck_start[j] = start
-            ck_true[j] = take
-            ck_active[j] = True
+        with _Phase(self, "inputs"):
+            toks, active, temps, topk = self._decode_arrays()
+            pref = sorted(
+                (self._srank(s), i) for i, s in enumerate(self.slots)
+                if s is not None and s.prefill_pos is not None)
+            lanes: list[tuple[int, int, int]] = []
+            ck_tok = np.zeros((K, C), np.int32)
+            ck_slot = np.zeros((K,), np.int32)
+            ck_start = np.zeros((K,), np.int32)
+            ck_true = np.ones((K,), np.int32)   # 1 keeps unused lanes in-range
+            ck_active = np.zeros((K,), bool)
+            ck_tables = (np.full((K, self.max_blocks), self.num_blocks,
+                                 np.int32) if self.paged else None)
+            for j, (_, i) in enumerate(pref[:K]):
+                s = self.slots[i]
+                start = s.prefill_pos
+                take = min(C, len(s.prefill_toks) - start)
+                ck_tok[j, :take] = s.prefill_toks[start:start + take]
+                ck_slot[j] = i
+                ck_start[j] = start
+                ck_true[j] = take
+                ck_active[j] = True
+                if self.paged:
+                    ck_tables[j] = s.prefill_table
+                lanes.append((i, start, take))
+            args = [self.params, self.cache, jnp.asarray(toks),
+                    jnp.asarray(active), jnp.asarray(ck_tok),
+                    jnp.asarray(ck_slot), jnp.asarray(ck_start),
+                    jnp.asarray(ck_true), jnp.asarray(ck_active)]
             if self.paged:
-                ck_tables[j] = s.prefill_table
-            lanes.append((i, start, take))
-        args = [self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(active), jnp.asarray(ck_tok),
-                jnp.asarray(ck_slot), jnp.asarray(ck_start),
-                jnp.asarray(ck_true), jnp.asarray(ck_active)]
-        if self.paged:
-            args.append(jnp.asarray(ck_tables))
-        logits, ck_logits, self.cache = self.model.jitted_mixed_step(
-            K, C, self.mesh)(*args)
+                args.append(jnp.asarray(ck_tables))
+        with _Phase(self, "dispatch"):
+            logits, ck_logits, self.cache = self.model.jitted_mixed_step(
+                K, C, self.mesh)(*args)
         self.prefill_chunks += len(lanes)
         if active.any():
             self._finish_decode(logits, temps, topk, done)
@@ -1397,7 +1461,8 @@ class Scheduler:
             s = self.slots[i]
             s.prefill_pos = start + take
             if s.prefill_pos >= len(s.prefill_toks):
-                self._complete_prefill(i, s, ck_logits[j], done)
+                with _Phase(self, "first_token"):
+                    self._complete_prefill(i, s, ck_logits[j], done)
 
     def _complete_prefill(self, i: int, s: _Slot, logits_row,
                           done: list[FinishedRequest]) -> None:
