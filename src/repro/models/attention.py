@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import model_axis_size, shard_act
+from repro.kernels.paged_attention import paged_decode_attention
 from .layers import (head_rmsnorm_apply, linear_apply, linear_spec,
                      rmsnorm_spec, rmsnorm_apply, rope)
 from .spec import ParamSpec
@@ -186,18 +187,24 @@ def gqa_decode_attn(p, cfg: ModelConfig, x, cache_k, cache_v, pos, *,
 
 def gqa_decode_attn_paged(p, cfg: ModelConfig, x, arena_k, arena_v, bt, pos,
                           active, *, window: int = 0,
-                          theta: float | None = None, backend: str = "xla"):
+                          theta: float | None = None, backend: str = "xla",
+                          layer=None):
     """One-token decode against a block-paged cache.
 
-    x [B,1,d]; arena_k/v [nb+1, block, KV, hd]; bt [B, max_blocks] int32;
-    pos [B] int32; active [B] bool.  Windowed layers address the arena
-    through the ring index ``pos % W`` (W = min(window, logical length)),
-    reusing the low entries of the same block table — ring blocks are
-    therefore never prefix-shared (the scheduler disables prefix caching
-    for windowed models).  Returns (y [B,1,d], new arenas).
-    """
+    x [B,1,d]; bt [B, max_blocks] int32; pos [B] int32; active [B] bool.
+    Full-attention layers take the group's whole layer stack of arenas
+    arena_k/v [L, nb+1, block, KV, hd] and ``layer`` (an int32 scalar)
+    indexing it: the token is written there in place and the paged
+    attention kernel (``kernels/paged_attention.py``) reads each row's
+    live blocks from it.  Windowed layers take their own arenas
+    [nb+1, block, KV, hd] and address them through the ring index
+    ``pos % W`` (W = min(window, logical length)), reusing the low entries
+    of the same block table — ring blocks are therefore never
+    prefix-shared (the scheduler disables prefix caching for windowed
+    models) — and attend over the gathered ring.  Returns (y [B,1,d], new
+    arenas)."""
     B = x.shape[0]
-    nb1, blk, KV, hd = arena_k.shape
+    nb1, blk, KV, hd = arena_k.shape[-4:]
     sentinel = nb1 - 1
     theta = cfg.rope_theta if theta is None else theta
     T_logical = bt.shape[1] * blk
@@ -208,19 +215,23 @@ def gqa_decode_attn_paged(p, cfg: ModelConfig, x, arena_k, arena_v, bt, pos,
     wp = pv % W if window else pv
     phys = jnp.take_along_axis(bt, (wp // blk)[:, None], 1)[:, 0]
     phys = jnp.where(active, phys, sentinel)
-    arena_k = arena_k.at[phys, wp % blk].set(k[:, 0])
-    arena_v = arena_v.at[phys, wp % blk].set(v[:, 0])
+    at = (phys, wp % blk) if layer is None else (layer, phys, wp % blk)
+    arena_k = arena_k.at[at].set(k[:, 0])
+    arena_v = arena_v.at[at].set(v[:, 0])
+    scale = 1.0 / np.sqrt(cfg.head_dim)
+    if not window:
+        lengths = jnp.where(active, pv + 1, 0)
+        ctx = paged_decode_attention(q[:, 0], arena_k, arena_v, bt, lengths,
+                                     layer, scale=scale)
+        y = linear_apply(p["o"], ctx.reshape(B, 1, -1), backend)
+        return y, arena_k, arena_v
     nblk = -(-W // blk)
     gk = arena_k[bt[:, :nblk]].reshape(B, nblk * blk, KV, hd)[:, :W]
     gv = arena_v[bt[:, :nblk]].reshape(B, nblk * blk, KV, hd)[:, :W]
     idx = jnp.arange(W)
-    if window:
-        abs_pos = pv[:, None] - jnp.mod(pv[:, None] - idx[None, :], W)
-        valid = abs_pos >= 0
-    else:
-        valid = idx[None, :] <= pv[:, None]
-    mask = valid[:, None, None, None, :]                  # [B,1,1,1,W]
-    ctx = _gqa_scores_ctx(q, gk, gv, mask, 1.0 / np.sqrt(cfg.head_dim))
+    abs_pos = pv[:, None] - jnp.mod(pv[:, None] - idx[None, :], W)
+    mask = (abs_pos >= 0)[:, None, None, None, :]         # [B,1,1,1,W]
+    ctx = _gqa_scores_ctx(q, gk, gv, mask, scale)
     y = linear_apply(p["o"], ctx, backend)
     return y, arena_k, arena_v
 

@@ -270,7 +270,7 @@ def _enc_kv(p, cfg, bd, enc_out, cache, want_cache, backend):
 # ---------------------------------------------------------------------------
 
 def block_decode(p, cfg: ModelConfig, bd: BlockDef, x, cache: dict, pos,
-                 plans=None, paged=None, active=None):
+                 plans=None, paged=None, active=None, layer=None):
     """``paged``: None for the dense slot-pool layout, else
     ``(block_tables [B, max_blocks], active [B])`` — attention leaves are
     block arenas addressed through the table; SSM/cross leaves are
@@ -278,7 +278,9 @@ def block_decode(p, cfg: ModelConfig, bd: BlockDef, x, cache: dict, pos,
     every per-slot cache write: rows mid-chunked-prefill (and retired/free
     rows) must not have their state touched by the fused decode pass —
     paged attention leaves are already protected by the sentinel-block
-    redirect, dense attention rows and SSM state/conv need the mask."""
+    redirect, dense attention rows and SSM state/conv need the mask.
+    ``layer`` (paged full attention only): the k/v leaves are the group's
+    whole layer stack of arenas and ``layer`` the index of this one."""
     backend = plans if plans is not None else cfg.tt.backend_spec
     h = rmsnorm_apply(p["ln1"], x, cfg.norm_eps)
     new_cache = dict(cache)
@@ -288,7 +290,8 @@ def block_decode(p, cfg: ModelConfig, bd: BlockDef, x, cache: dict, pos,
                 bt, pact = paged
                 y, nk, nv = gqa_decode_attn_paged(
                     p["attn"], cfg, h, cache["k"], cache["v"], bt, pos, pact,
-                    window=bd.window, theta=bd.theta, backend=backend)
+                    window=bd.window, theta=bd.theta, backend=backend,
+                    layer=layer)
             else:
                 y, nk, nv = gqa_decode_attn(p["attn"], cfg, h, cache["k"],
                                             cache["v"], pos, window=bd.window,
@@ -357,21 +360,40 @@ def group_decode(params, cfg: ModelConfig, group: Group, x, caches, pos,
     """Scan decode over stacked (params, caches).  Returns (x, new_caches).
     ``paged`` = (block_tables, active) switches attention leaves to the
     block-arena layout; ``active`` masks per-slot writes (see
-    block_decode)."""
+    block_decode).
+
+    Paged full-attention arenas ride the scan's carry as whole layer
+    stacks instead of being sliced per layer: the new token's K/V is
+    written into the stack in place and the paged attention kernel reads
+    the layer's live blocks from it by index, so no layer's arena is
+    copied out and back each step."""
     period, count = group
+    whole = {f"b{i}" for i, bd in enumerate(period)
+             if paged is not None and bd.mixer == "gqa" and not bd.window}
+    arenas = {b: {n: caches[b][n] for n in ("k", "v")} for b in whole}
+    sliced = {b: ({n: l for n, l in c.items() if n not in ("k", "v")}
+                  if b in whole else c) for b, c in caches.items()}
 
-    def body(x, inp):
-        layer_params, layer_caches = inp
-        new = {}
+    def body(carry, inp):
+        x, arenas = carry
+        layer_params, layer_caches, layer = inp
+        new, new_arenas = {}, {}
         for i, bd in enumerate(period):
-            x, c = block_decode(layer_params[f"b{i}"], cfg, bd, x,
-                                layer_caches[f"b{i}"], pos, plans=plans,
-                                paged=paged, active=active)
-            new[f"b{i}"] = c
-        return x, new
+            b = f"b{i}"
+            c = dict(layer_caches[b], **arenas.get(b, {}))
+            x, c = block_decode(layer_params[b], cfg, bd, x, c, pos,
+                                plans=plans, paged=paged, active=active,
+                                layer=layer if b in whole else None)
+            if b in whole:
+                new_arenas[b] = {n: c.pop(n) for n in ("k", "v")}
+            new[b] = c
+        return (x, new_arenas), new
 
-    x, new_caches = jax.lax.scan(body, x, (params, caches),
-                                 unroll=SCAN_UNROLL or 1)
+    (x, arenas), new_caches = jax.lax.scan(
+        body, (x, arenas), (params, sliced, jnp.arange(count)),
+        unroll=SCAN_UNROLL or 1)
+    for b, a in arenas.items():
+        new_caches[b] = dict(new_caches[b], **a)
     return x, new_caches
 
 

@@ -283,6 +283,7 @@ class Scheduler:
         self.first_tokens = 0             # first tokens emitted
         self.ttft_queue_s = 0.0           # submit -> admission, summed
         self.ttft_prefill_s = 0.0         # admission -> first token, summed
+        self.decode_kv_tokens = 0         # positions decode rows attend over
         # shared across Scheduler instances of the same model: a server
         # creating one Scheduler per batch must not recompile the pick
         self._pick = model._jit_get("pick", self._build_pick)
@@ -371,7 +372,8 @@ class Scheduler:
                "host_steps": self.host_steps,
                "first_tokens": self.first_tokens,
                "ttft_queue_s": self.ttft_queue_s,
-               "ttft_prefill_s": self.ttft_prefill_s}
+               "ttft_prefill_s": self.ttft_prefill_s,
+               "decode_kv_tokens": self.decode_kv_tokens}
         if self.chunk_prefill:
             out.update(chunk_size=self.chunk_size,
                        prefill_budget=self.prefill_budget,
@@ -406,6 +408,7 @@ class Scheduler:
         self.host_s, self.host_max_s = {}, {}
         self.host_steps = self.first_tokens = 0
         self.ttft_queue_s = self.ttft_prefill_s = 0.0
+        self.decode_kv_tokens = 0
         if self.paged:
             self.block_hwm = self.allocator.in_use
             self.prefix_hit_tokens = self.prefix_prompt_tokens = 0
@@ -1360,7 +1363,10 @@ class Scheduler:
     def _decode_arrays(self):
         """Host-side inputs of the masked decode pass.  Mid-prefill slots
         are NOT decode-active: the decode pass's per-slot writes are
-        masked off for them, leaving their partially-built rows alone."""
+        masked off for them, leaving their partially-built rows alone.
+        Counts the positions the active rows attend over (``pos + 1``:
+        the prompt and every token generated, the last one written this
+        pass) into ``decode_kv_tokens``."""
         B = self.num_slots
         toks = np.zeros((B, 1), np.int32)
         active = np.zeros((B,), bool)
@@ -1372,6 +1378,7 @@ class Scheduler:
                 active[i] = True
                 temps[i] = s.temperature
                 topk[i] = s.top_k
+                self.decode_kv_tokens += s.prompt_len + len(s.tokens)
         return toks, active, temps, topk
 
     def _finish_decode(self, logits, temps, topk,

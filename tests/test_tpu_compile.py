@@ -1,4 +1,4 @@
-"""Compile the Pallas TT kernels for a described TPU v5e chip.
+"""Compile the Pallas kernels for a described TPU v5e chip.
 
 Interpret mode (every other kernel test) accepts bodies that the chip's
 compiler, Mosaic, refuses: reshapes that split the lane dim, matmuls with
@@ -6,9 +6,10 @@ two contracting dims, more VMEM than the kernel may use.  These tests
 compile each kernel of the serving path — the per-step kernel and the
 fused d=2 / d≥3 chains, fp and int8-resident — at the deepseek-7b FFN
 plans (``configs/deepseek_7b.py``, ``--tt ffn``, rank 16, ``min_factor=8``)
-and at one d=3 plan of the same widths, for a ``v5e:2x2`` topology that is
-described, not attached.  Nothing runs; a passing compile is not a chip
-run.
+and at one d=3 plan of the same widths, and the paged decode attention
+kernel at the deepseek-7b and granite-8b pools, for a ``v5e:2x2``
+topology that is described, not attached.  Nothing runs; a passing
+compile is not a chip run.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and test collection happens in
@@ -26,7 +27,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import hw
 from repro.core.flops import prod
 from repro.core.packing import fused_chain_batch_tile, fused_chain_vmem_bytes
-from repro.kernels import tt_contract
+from repro.kernels import paged_attention, tt_contract
 from repro.kernels.ops import tt_forward
 from repro.kernels.plan import plan_tt_forward
 
@@ -162,3 +163,62 @@ def test_fused_tile_needs_and_fits_the_vmem_limit(one_chip, monkeypatch):
             _compile(chain, x, *packed)
     finally:
         jax.clear_caches()
+
+
+# (query heads, KV heads, arena blocks + sentinel, slots) of the paged
+# pools the benchmark serves: deepseek-7b (MHA 32×128, 112 blocks of 64,
+# 8 slots) and granite-8b (GQA, 8 KV heads, 544 blocks, 16 slots)
+PAGED_POOLS = {"deepseek-7b": (32, 32, 113, 8), "granite-8b": (32, 8, 545, 16)}
+
+
+@pytest.mark.parametrize("pool", sorted(PAGED_POOLS))
+def test_paged_decode_attention_compiles_for_v5e(one_chip, pool):
+    H, KV, nb1, B = PAGED_POOLS[pool]
+    hd, blk, max_blocks = 128, 64, 64
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,  # noqa: E731
+                                                  sharding=one_chip)
+    stack = spec((2, nb1, blk, KV, hd), jnp.bfloat16)
+
+    def attend(q, ak, av, bt, lengths, layer):
+        return paged_attention.paged_decode_attention(
+            q, ak, av, bt, lengths, layer, scale=hd ** -0.5,
+            interpret=False)
+
+    hlo = _compile(attend, spec((B, H, hd), jnp.bfloat16), stack, stack,
+                   spec((B, max_blocks), jnp.int32), spec((B,), jnp.int32),
+                   spec((), jnp.int32))
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 1
+
+
+def test_paged_decode_step_reads_the_arena_in_the_kernel(one_chip,
+                                                         monkeypatch):
+    """The compiled decode step of a small paged model (deepseek-7b smoke,
+    dense FFNs, heads of the full model's 128 lanes, which the kernel's
+    block copies need) attends in the paged kernel, and nothing in it is
+    an f32 copy of a cache gathered over every slot's logical length."""
+    import dataclasses
+    from repro.configs import build, get_config
+    from repro.configs.base import TTConfig
+    monkeypatch.setattr(paged_attention, "_interpret_default", lambda: False)
+    cfg = dataclasses.replace(
+        get_config("deepseek-7b", "smoke", tt=TTConfig(enabled=False)),
+        head_dim=128)
+    model = build(cfg, param_dtype=jnp.bfloat16)
+    B, nb, blk, T = 4, 12, 16, 128
+    on_chip = lambda s: jax.ShapeDtypeStruct(  # noqa: E731
+        s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(on_chip, model.abstract_params())
+    cache = jax.tree.map(on_chip, model.paged_cache_shapes(B, nb, blk, T))
+    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    act = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
+    jax.clear_caches()
+    try:
+        hlo = model.jitted_decode_step_masked().lower(
+            params, cache, tok, act).compile().as_text()
+    finally:
+        jax.clear_caches()
+    calls = [ln for ln in hlo.splitlines()
+             if "tpu_custom_call" in ln and "_paged_decode_attn_call" in ln]
+    assert calls
+    gathered = f"f32[{B * (T // blk)},{blk},{cfg.num_kv_heads},{cfg.head_dim}]"
+    assert gathered not in hlo
