@@ -5,11 +5,19 @@ TT plan's modes and ranks), never on how the program implements it, so
 they stay fixed while later changes swap kernels underneath.  Operations
 count a multiply and an add as two; a TT layer is counted at its
 factorised cost (the paper's Eq. 11/13 without bias).
+
+The TT arithmetic here is shared by every family.  ``Dims`` counts a
+dense decoder (MHA or GQA, one gated FFN per layer); it is what the dense
+reference module's ``dims`` gives.  A family with other layer equations
+gives its own object with the same methods from its own reference module
+(``bench/reference.py`` names them).
 """
 from __future__ import annotations
 
 import dataclasses
 import math
+
+KV_BYTES = 2                    # bf16 arenas
 
 
 def tt_flops_per_row(ns, ms, ranks) -> int:
@@ -35,6 +43,7 @@ def tt_call_bytes(ns, ms, ranks, rows: int, act_bytes: int = 2,
 
 @dataclasses.dataclass(frozen=True)
 class Dims:
+    """A dense decoder's counts: every layer the same attention and FFN."""
     layers: int
     d_model: int
     heads: int
@@ -52,38 +61,46 @@ class Dims:
                    tuple(tuple(tuple(int(v) for v in part) for part in c)
                          for c in ffn_chains))
 
+    def token_flops(self, ctx: int) -> int:
+        """One token through every layer, attending to ``ctx`` positions
+        (itself included); the LM head is counted apart."""
+        d, q = self.d_model, self.heads * self.head_dim
+        kv = self.kv_heads * self.head_dim
+        proj = 2 * d * (2 * q + 2 * kv)
+        attn = 4 * ctx * q
+        ffn = sum(tt_flops_per_row(*c) for c in self.ffn)
+        return self.layers * (proj + attn + ffn)
 
-def token_flops(dims: Dims, ctx: int) -> int:
-    """One token through every layer, attending to ``ctx`` positions
-    (itself included); the LM head is counted apart."""
-    d, q = dims.d_model, dims.heads * dims.head_dim
-    kv = dims.kv_heads * dims.head_dim
-    proj = 2 * d * (2 * q + 2 * kv)
-    attn = 4 * ctx * q
-    ffn = sum(tt_flops_per_row(*c) for c in dims.ffn)
-    return dims.layers * (proj + attn + ffn)
+    def prompt_flops(self, prompt_len: int) -> int:
+        """A whole prompt, position p attending to p + 1 positions."""
+        P = prompt_len
+        q = self.heads * self.head_dim
+        return (P * self.token_flops(0)
+                + self.layers * 4 * q * (P * (P + 1) // 2))
+
+    def head_flops(self) -> int:
+        return 2 * self.d_model * self.vocab
+
+    def tt_work(self, rows: int, calls_per_matrix: int) -> tuple[int, int]:
+        """(flops, bytes) of the FFN's TT layers over ``rows`` token rows
+        spread over ``calls_per_matrix`` calls of each matrix per layer:
+        the rows move once, the cores once per call."""
+        flops = byts = 0
+        for c in self.ffn:
+            flops += self.layers * rows * tt_flops_per_row(*c)
+            act = tt_call_bytes(*c, rows=rows) - tt_call_bytes(*c, rows=0)
+            byts += self.layers * (act + calls_per_matrix
+                                   * tt_call_bytes(*c, rows=0))
+        return flops, byts
+
+    def kv_bytes_per_token(self) -> int:
+        """The cache one position holds over every layer: a K and a V row
+        of ``kv_heads × head_dim`` values."""
+        return self.layers * 2 * self.kv_heads * self.head_dim * KV_BYTES
 
 
-def prompt_flops(dims: Dims, prompt_len: int) -> int:
-    """A whole prompt, position p attending to p + 1 positions."""
-    P = prompt_len
-    q = dims.heads * dims.head_dim
-    return P * token_flops(dims, 0) + dims.layers * 4 * q * (P * (P + 1) // 2)
-
-
-def head_flops(dims: Dims) -> int:
-    return 2 * dims.d_model * dims.vocab
-
-
-def tt_work(dims: Dims, rows: int, calls_per_matrix: int
-            ) -> tuple[int, int]:
-    """(flops, bytes) of the FFN's TT layers over ``rows`` token rows
-    spread over ``calls_per_matrix`` calls of each matrix per layer: the
-    rows move once, the cores once per call."""
-    flops = byts = 0
-    for c in dims.ffn:
-        flops += dims.layers * rows * tt_flops_per_row(*c)
-        act = tt_call_bytes(*c, rows=rows) - tt_call_bytes(*c, rows=0)
-        byts += dims.layers * (act + calls_per_matrix
-                               * tt_call_bytes(*c, rows=0))
-    return flops, byts
+# the counts as functions of the dims, the form bench/tests call
+token_flops = Dims.token_flops
+prompt_flops = Dims.prompt_flops
+head_flops = Dims.head_flops
+tt_work = Dims.tt_work

@@ -1,11 +1,12 @@
 """The TT kernels' share of their roofline: the least time the chip needs
 for the FFN's TT work the window served, max(operations / bf16 peak,
 bytes / HBM bandwidth), over the kernels' device time in the trace.
-Operations and bytes come from bench/cost.py over the plan's modes and
-ranks and the token rows served (not the padded rows the kernels run):
-each call moves its rows in and out once and its cores once.  On one
-v5e the bytes bound it in every cell (PERF.md, section 5)."""
-from bench import cost, trace
+Operations and bytes come from the window's dims (``tt_work``, over the
+plan's modes and ranks) and the token rows served (not the padded rows
+the kernels run): each call moves its rows in and out once and its cores
+once.  On one v5e the bytes bound it in every cell (PERF.md, section
+5)."""
+from bench import trace
 from bench.metrics.tt_ms import KERNELS
 
 SOURCE = "device_trace"
@@ -22,7 +23,7 @@ def read(w):
     if not k or not rows:
         return None
     calls = w.stats["steps_run"] + w.stats.get("prefill_chunks", 0)
-    flops, byts = cost.tt_work(w.dims, rows, calls)
+    flops, byts = w.dims.tt_work(rows, calls)
     least = max(flops / w.peaks["bf16_flops_per_s"],
                 byts / w.peaks["hbm_bytes_per_s"])
     return 100.0 * least / k

@@ -2,6 +2,21 @@
 gated FFNs whose matrices are dense or tensor-train (TT) factorised: the
 yardstick the served tokens are compared with.
 
+This is the dense reference module.  A configuration file picks its
+module by ``"reference": "<name>"`` (``bench/reference_<name>.py``), or
+this one without the key; ``bench/run.py`` loads it by file path and
+reads everything that depends on the layer equations from it:
+
+- ``Reference(params, cfg, precision)``: ``.logits(tokens, first)``, the
+  float32 logits; ``precision="int8"`` is the control.
+- ``published(model_cfg)``: {published key: the value the program runs};
+  the run is refused where the file lacks a key or gives another value.
+- ``dims(cfg, params)``: the counts the window and the readers use:
+  ``token_flops(ctx)``, ``prompt_flops(P)``, ``head_flops()``,
+  ``tt_work(rows, calls)``, ``kv_bytes_per_token()``.
+- ``LAWS`` (optional): {leaf name: fn(shape) -> (law, std)} for leaves
+  ``bench/weights.py`` has no law of its own for.  This module has none.
+
 It imports nothing of the program.  It reads the weights the benchmark
 drew (``bench/weights.py``) by their names in the parameter tree, and the
 shape constants from the configuration file.  The mathematics:
@@ -37,7 +52,36 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import cost
+
 BUCKET = 512
+
+
+def published(c) -> dict:
+    """The published keys of a dense decoder, as the program runs them."""
+    return {"num_hidden_layers": c.num_layers, "hidden_size": c.d_model,
+            "num_attention_heads": c.num_heads,
+            "num_key_value_heads": c.num_kv_heads, "head_dim": c.head_dim,
+            "intermediate_size": c.d_ff, "vocab_size": c.vocab_size,
+            "rope_theta": c.rope_theta, "rms_norm_eps": c.norm_eps,
+            "tie_word_embeddings": c.tie_embeddings}
+
+
+def ffn_chains(params) -> list:
+    """(ns, ms, ranks) of each FFN matrix, from the cores' shapes."""
+    out = []
+    for name in ("gate", "up", "down"):
+        tt = params["g0"]["b0"]["ffn"][name].get("tt")
+        if tt is None:
+            continue
+        cores = [tt[f"c{t}"].shape[-4:] for t in range(len(tt))]
+        out.append((tuple(c[1] for c in cores), tuple(c[2] for c in cores),
+                    tuple(c[0] for c in cores) + (cores[-1][3],)))
+    return out
+
+
+def dims(cfg: dict, params) -> cost.Dims:
+    return cost.Dims.from_config(cfg, ffn_chains(params))
 
 
 def _fq(x, axis):

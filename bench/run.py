@@ -11,7 +11,12 @@ program's own entry point, draws its weights on the device from the seed,
 warms up the shapes the cell's traffic uses (set-up), offers the traffic
 to ``Scheduler.submit`` / ``Scheduler.step`` for ``--seconds`` (the
 window), then compares a sample of what it served with the plain float32
-reference (``bench/reference.py``).
+reference.  The configuration's reference module holds its layer
+equations: ``bench/reference_<name>.py`` where the file gives
+``"reference": "<name>"``, else ``bench/reference.py``, whose docstring
+states what the module gives; the published keys checked, the weight
+laws, the counts the readers use and the reference itself all come from
+it.
 
 Standard output ends with one JSON line: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics, or with
@@ -38,8 +43,10 @@ import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
+import types  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "bench")
@@ -61,6 +68,12 @@ class Cell:
     chips: int
     end_to_end: list              # [(name, unit)]
     per_layer: list               # [(name, unit)]
+    reference: types.ModuleType | None = None   # load_reference(config)
+
+    def __post_init__(self):
+        # the one place a run's reference module is resolved
+        if self.reference is None:
+            self.reference = load_reference(self.config)
 
 
 def _json(path):
@@ -94,6 +107,27 @@ def reader(name: str):
     return mod
 
 
+def load_reference(config: dict, where: str = BENCH) -> types.ModuleType:
+    """The module of the configuration's layer equations, found in
+    ``where`` by the name the file gives it."""
+    name = config.get("reference")
+    if name is not None and not re.fullmatch(r"\w+", str(name)):
+        raise Refused(f"reference {name!r} is not a module name")
+    stem = "reference" if name is None else f"reference_{name}"
+    path = os.path.join(where, f"{stem}.py")
+    if not os.path.isfile(path):
+        raise Refused(f"the configuration names reference {name!r}, and "
+                      f"there is no {os.path.relpath(path, ROOT)}")
+    mod = sys.modules.get(f"bench.{stem}")
+    if mod is not None and os.path.samefile(mod.__file__, path):
+        return mod
+    spec = importlib.util.spec_from_file_location(f"bench.{stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def device_check(chips: int):
     """The first device, its description and peaks; refuses anything but
     a TPU of a known kind with enough chips."""
@@ -116,8 +150,7 @@ def device_check(chips: int):
 
 
 def build_model(config: dict):
-    """The model as ``launch/serve.main`` builds it, checked against the
-    configuration file."""
+    """The model as ``launch/serve.main`` builds it."""
     import jax.numpy as jnp
     from repro.configs import build, get_config
     from repro.configs.base import TTConfig
@@ -130,32 +163,21 @@ def build_model(config: dict):
         get_config(config["arch"], config["variant"], tt=tcfg),
         rope_theta=float(config["rope_theta"]),
         norm_eps=float(config["rms_norm_eps"]))
-    model = build(mcfg, param_dtype=dtype)
-    c = model.cfg
-    ran = {"num_hidden_layers": c.num_layers, "hidden_size": c.d_model,
-           "num_attention_heads": c.num_heads,
-           "num_key_value_heads": c.num_kv_heads, "head_dim": c.head_dim,
-           "intermediate_size": c.d_ff, "vocab_size": c.vocab_size,
-           "rope_theta": c.rope_theta, "rms_norm_eps": c.norm_eps,
-           "tie_word_embeddings": c.tie_embeddings}
+    return build(mcfg, param_dtype=dtype)
+
+
+def check_published(config: dict, model, ref: types.ModuleType):
+    """Refuses a configuration file that lacks a key ``ref.published``
+    names, or whose value departs from what the program runs."""
+    ran = ref.published(model.cfg)
+    missing = sorted(k for k in ran if k not in config)
+    if missing:
+        raise Refused(f"the configuration file lacks the published keys "
+                      f"{missing} that its reference module checks")
     bad = {k: (v, config[k]) for k, v in ran.items() if v != config[k]}
     if bad:
         raise Refused(f"the program's {config['arch']} departs from the "
                       f"configuration file: {bad}")
-    return model
-
-
-def ffn_chains(params) -> list:
-    """(ns, ms, ranks) of each FFN matrix, from the cores' shapes."""
-    out = []
-    for name in ("gate", "up", "down"):
-        tt = params["g0"]["b0"]["ffn"][name].get("tt")
-        if tt is None:
-            continue
-        cores = [tt[f"c{t}"].shape[-4:] for t in range(len(tt))]
-        out.append((tuple(c[1] for c in cores), tuple(c[2] for c in cores),
-                    tuple(c[0] for c in cores) + (cores[-1][3],)))
-    return out
 
 
 def compile_counter():
@@ -190,8 +212,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     """One run of ``cell``; ``chip`` is ``device_check``'s answer, or None
     to run on whatever device JAX has (the CPU tests)."""
     import jax
-    from bench import check, cost, traffic, weights, window
-    from bench.reference import Reference
+    from bench import check, traffic, weights, window
     from repro.kernels import plan as ttplan
     from repro.serving.scheduler import Request, Scheduler
 
@@ -203,10 +224,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
         device = {"platform": dev.platform, "kind": dev.device_kind,
                   "count": 1}
         peaks = next(iter(PEAKS.values()))
-    cfg, srv = cell.config, cell.config["serving"]
+    cfg, srv, ref = cell.config, cell.config["serving"], cell.reference
     model = build_model(cfg)
-    params = weights.make_params(model.abstract_params(), seed)
-    dims = cost.Dims.from_config(cfg, ffn_chains(params))
+    check_published(cfg, model, ref)
+    params = weights.make_params(model.abstract_params(), seed,
+                                 getattr(ref, "LAWS", None))
+    dims = ref.dims(cfg, params)
     sched = Scheduler(model, params, num_slots=int(srv["num_slots"]),
                       cache_len=int(srv["cache_len"]), eos_id=None,
                       paged=True, block_size=int(srv["block_size"]),
@@ -258,9 +281,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     prompts = {r.uid: r.prompt for r in reqs}
     pairs = check.sample(finished, prompts, seed)
     t_ref = time.perf_counter()
-    ref = Reference(params, cfg)
-    ctrl = Reference(params, cfg, "int8") if control else None
-    prog, ctrl_nums, n_served = check.compare(ref, pairs, ctrl)
+    ctrl = ref.Reference(params, cfg, "int8") if control else None
+    prog, ctrl_nums, n_served = check.compare(ref.Reference(params, cfg),
+                                              pairs, ctrl)
     t_ref = time.perf_counter() - t_ref
     limits = cfg["correct"]
     checks = check.checks(prog, limits)

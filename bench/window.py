@@ -20,7 +20,7 @@ import time
 
 import numpy as np
 
-from bench import cost, trace
+from bench import trace
 
 
 def _annotate(name):
@@ -43,7 +43,7 @@ class Window:
     stats: dict                  # Scheduler.stats() counters of the window
     chunk_size: int
     num_slots: int
-    dims: cost.Dims
+    dims: object                 # the reference module's dims(cfg, params)
     peaks: dict
     trace: dict | None = None    # normalized device trace (--trace 1)
     host: dict = dataclasses.field(default_factory=dict)  # heap, GC, steps
@@ -95,10 +95,10 @@ class Window:
                 if not self.in_window(t):
                     continue
                 if i == 0:
-                    fl += cost.prompt_flops(d, P)
+                    fl += d.prompt_flops(P)
                 else:
-                    fl += cost.token_flops(d, P + i)
-                fl += cost.head_flops(d)
+                    fl += d.token_flops(P + i)
+                fl += d.head_flops()
         return fl
 
 
@@ -134,7 +134,7 @@ class _Collections:
 
 
 def run(sched, reqs, seconds: float, *, request_cls, setup_t0: float,
-        dims: cost.Dims, peaks: dict, trace_dir: str | None = None,
+        dims, peaks: dict, trace_dir: str | None = None,
         counters=None) -> Window:
     """Offer ``reqs`` (``bench.traffic.Req``) for ``seconds`` and return
     the window.  A backlog (every request due at the window's start) is
